@@ -19,7 +19,9 @@ cost_analysis() on the SPMD-partitioned executable reports PER-CHIP figures
 standard ring factors; the group size n is approximated by the mesh axis the
 collective most plausibly runs over (model=16) -- noted as approximate.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link.
+Chip peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; a kind
+that is not in the table raises.  The dry-run's production meshes describe
+v5e pods, so their records are read against ``DRYRUN_DEVICE_KIND``.
 """
 
 from __future__ import annotations
@@ -31,9 +33,25 @@ import sys
 
 from repro.configs import INPUT_SHAPES, get_config
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Published per-chip peaks, keyed by jax's device_kind.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, HBM at 819 GB/s, 1,600 Gbit/s
+# of chip-to-chip interconnect over 4 links).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"   # the pods the dry-run meshes describe
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``; unknown raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"add them to benchmarks/roofline.py PEAKS "
+                       f"(known: {sorted(PEAKS)})") from None
+
+
 SERVER_NIC_BW = 12.5e9  # 100 Gb/s front-end NIC: client uploads enter here
 DEFAULT_GROUP = 16  # model-axis size; collectives are predominantly TP
 
@@ -74,10 +92,11 @@ def model_flops(rec: dict) -> float:
 
 
 def analyze(rec: dict) -> dict:
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
     chips = 512 if rec["mesh"] == "2x16x16" else 256
-    t_compute = rec["flops"] / PEAK_FLOPS
-    t_memory = rec["bytes_accessed"] / HBM_BW
-    t_coll = wire_bytes(rec.get("collectives", {})) / LINK_BW
+    t_compute = rec["flops"] / peaks["bf16_flops"]
+    t_memory = rec["bytes_accessed"] / peaks["hbm_bw"]
+    t_coll = wire_bytes(rec.get("collectives", {})) / peaks["link_bw"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)

@@ -94,6 +94,8 @@ def main() -> None:
     quick = "--quick" in sys.argv
 
     from benchmarks import kernel_bench, paper_claims
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     rows = []
     which = args or ["golomb", "wire", "kernels", "chunked", "ingest",

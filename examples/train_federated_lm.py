@@ -1,6 +1,6 @@
 """End-to-end driver: federated STC training of a transformer LM on the
 distributed train_step (shard_map over client axes, GSPMD tensor parallelism),
-on a debug mesh of fake CPU devices.
+on a mesh of the attached chips, one client per chip.
 
     PYTHONPATH=src python examples/train_federated_lm.py \
         [--arch smollm-135m] [--steps 200] [--protocol stc] [--full]
@@ -8,11 +8,10 @@ on a debug mesh of fake CPU devices.
 Default trains a reduced (~10M-param) variant of the chosen architecture for a
 few hundred steps on synthetic token data -- small enough for CPU, while
 exercising the REAL production code path (the same make_train_step the
-512-chip dry-run lowers).  --full uses the full assigned config (TPU-sized).
+chip runs).  --full uses the full assigned config (TPU-sized).  On the CPU,
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` gives N virtual
+devices and so N clients.
 """
-
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 import dataclasses
@@ -24,8 +23,11 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import make_lm_tokens
-from repro.launch.mesh import make_debug_mesh
-from repro.launch.train import TrainConfig, init_train_state, make_train_step
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.launch.train import (TrainConfig, batch_shardings,
+                                init_train_state, make_train_step,
+                                state_shardings)
 
 
 def main():
@@ -43,8 +45,12 @@ def main():
     ap.add_argument("--eval-every", type=int, default=25)
     args = ap.parse_args()
 
-    mesh = make_debug_mesh(data=2, model=2)
-    n_clients = 2
+    use_compile_cache()
+    mesh = make_mesh()                  # every attached chip on "data"
+    n_clients = mesh.shape["data"]
+    if args.batch % n_clients:
+        raise SystemExit(f"--batch {args.batch} must split over "
+                         f"{n_clients} clients")
 
     if args.full:
         cfg = get_config(args.arch)
@@ -63,6 +69,7 @@ def main():
                      local_iters=delay)
     state = init_train_state(cfg, tc, n_clients=n_clients,
                              key=jax.random.PRNGKey(0))
+    state = jax.device_put(state, state_shardings(state, mesh))
     step = make_train_step(cfg, mesh, tc)
 
     tokens = make_lm_tokens(seed=0, n_tokens=1 << 22, vocab=cfg.vocab_size)
@@ -80,7 +87,7 @@ def main():
         if cfg.n_prefix_tokens:
             batch["prefix"] = jnp.zeros((b, cfg.n_prefix_tokens, cfg.d_model),
                                         jnp.float32)
-        return batch
+        return jax.device_put(batch, batch_shardings(batch, mesh, b))
 
     t0 = time.time()
     losses = []

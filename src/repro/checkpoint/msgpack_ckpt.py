@@ -1,32 +1,19 @@
-"""Msgpack pytree checkpointing with zstd (or stdlib zlib) compression.
-
-``zstandard`` is an *optional* dependency: when it is missing we fall back to
-stdlib ``zlib``.  The codec is sniffed on restore via the zstd frame magic, so
-checkpoints written with either codec restore correctly whenever the matching
-decompressor is importable.
-"""
+"""Msgpack pytree checkpointing with zstd compression."""
 
 from __future__ import annotations
 
 import os
 import tempfile
-import zlib
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-try:  # optional: prefer zstd when available (better ratio + speed)
-    import zstandard
-except ImportError:  # pragma: no cover - exercised on minimal images
-    zstandard = None
+import zstandard
 
 __all__ = ["save_checkpoint", "restore_checkpoint",
            "save_state", "restore_state"]
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"  # little-endian 0xFD2FB528 frame header
 
 _BF16 = "bfloat16"
 
@@ -47,18 +34,10 @@ def _unpack_leaf(rec):
     return jnp.asarray(arr)
 
 
-def save_checkpoint(path: str, tree) -> None:
-    leaves, treedef = jax.tree.flatten(tree)
-    payload = msgpack.packb({
-        "treedef": str(treedef),  # structural fingerprint for validation
-        "leaves": [_pack_leaf(x) for x in leaves],
-    })
-    if zstandard is not None:
-        comp = zstandard.ZstdCompressor(level=3).compress(payload)
-    else:
-        comp = zlib.compress(payload, 6)
+def _write_compressed(path: str, payload: bytes) -> None:
+    """zstd-compress ``payload`` and replace ``path`` atomically."""
+    comp = zstandard.ZstdCompressor(level=3).compress(payload)
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    # atomic write
     fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)))
     try:
         with os.fdopen(fd, "wb") as f:
@@ -69,20 +48,23 @@ def save_checkpoint(path: str, tree) -> None:
         raise
 
 
+def _read_compressed(path: str) -> bytes:
+    with open(path, "rb") as f:
+        return zstandard.ZstdDecompressor().decompress(f.read())
+
+
+def save_checkpoint(path: str, tree) -> None:
+    leaves, treedef = jax.tree.flatten(tree)
+    _write_compressed(path, msgpack.packb({
+        "treedef": str(treedef),  # structural fingerprint for validation
+        "leaves": [_pack_leaf(x) for x in leaves],
+    }))
+
+
 def restore_checkpoint(path: str, like):
     """Restore into the structure of ``like`` (validates leaf count +
     treedef fingerprint)."""
-    with open(path, "rb") as f:
-        raw = f.read()
-    if raw[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise ImportError(
-                f"{path} is zstd-compressed but the 'zstandard' package is "
-                "not installed")
-        payload = zstandard.ZstdDecompressor().decompress(raw)
-    else:
-        payload = zlib.decompress(raw)
-    obj = msgpack.unpackb(payload)
+    obj = msgpack.unpackb(_read_compressed(path))
     leaves, treedef = jax.tree.flatten(like)
     if len(obj["leaves"]) != len(leaves):
         raise ValueError(
@@ -180,22 +162,6 @@ def _untag_state(x, classes: dict):
     raise ValueError(f"unknown state tag {tag!r}")
 
 
-def _write_compressed(path: str, payload: bytes) -> None:
-    if zstandard is not None:
-        comp = zstandard.ZstdCompressor(level=3).compress(payload)
-    else:
-        comp = zlib.compress(payload, 6)
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)))
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(comp)
-        os.replace(tmp, path)
-    except BaseException:
-        os.unlink(tmp)
-        raise
-
-
 def save_state(path: str, obj) -> None:
     """Serialize an arbitrary tagged-codec state object (see above) with
     the same compression + atomic-replace discipline as
@@ -207,15 +173,6 @@ def save_state(path: str, obj) -> None:
 def restore_state(path: str, classes: Optional[dict] = None):
     """Inverse of :func:`save_state`.  ``classes`` maps NamedTuple class
     names to their classes (needed to rebuild "nt" records)."""
-    with open(path, "rb") as f:
-        raw = f.read()
-    if raw[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise ImportError(
-                f"{path} is zstd-compressed but the 'zstandard' package is "
-                "not installed")
-        payload = zstandard.ZstdDecompressor().decompress(raw)
-    else:
-        payload = zlib.decompress(raw)
-    return _untag_state(msgpack.unpackb(payload, strict_map_key=False),
+    return _untag_state(msgpack.unpackb(_read_compressed(path),
+                                        strict_map_key=False),
                         classes or {})

@@ -11,7 +11,8 @@ reverse (see the lazy "kernel" backend lookup in :mod:`.compression`).
   expression MUST stay bit-identical everywhere it is evaluated (histogram
   kernel, refinement pass, tree sweep), so there is exactly one definition.
 * ``resolve_interpret`` -- backend autodetect for the kernels' ``interpret``
-  flag (interpret everywhere but on a real TPU).
+  flag: compiled on a TPU, interpreted on the CPU backend, and an error on
+  any other backend (a kernel never silently interprets off the CPU).
 * ``PASSES`` -- trace-time streaming-pass counter: every logical full sweep
   over the data records itself here, and tests assert the histogram selector
   stays within its ≤3-pass budget where bisection spends 33.
@@ -30,10 +31,21 @@ DEFAULT_CAP = 8192  # static refinement-gather capacity (candidate bin size)
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` -> interpret everywhere but on a real TPU backend."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    """``None`` -> compiled on a TPU, interpreted on the CPU backend.
+
+    Any other backend raises: the Pallas kernels here are written for the
+    TPU, and interpreting them on an accelerator would hide the device.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for 'tpu' and interpret on 'cpu'; the "
+        f"default backend is {backend!r} (pass interpret= explicitly)")
 
 
 def bin_index(a: jnp.ndarray, scale: jnp.ndarray, bins: int) -> jnp.ndarray:

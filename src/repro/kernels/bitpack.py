@@ -13,7 +13,9 @@ The host lays the bit tensor out as ``(32, rows, LANE)`` with word
 ``(32, block_rows, LANE)`` block and writes a ``(block_rows, LANE)`` uint32
 block: the shift-and-sum runs over the leading 32-axis, lanes stay 128-wide,
 and the summands are disjoint powers of two (no carries), so an integer sum
-IS the bitwise OR.
+IS the bitwise OR.  The TPU reduces only signed integers, so the kernel sums
+in int32 (bit 31 is the sign bit, and with no carries nothing wraps) and the
+words are bitcast to uint32 outside it.
 
 ``pack_bits_words_batched`` reduces a uniform-length ``(B, nbits)`` batch to
 ONE launch by word-aligning each row and flattening -- per-row word slices
@@ -57,11 +59,10 @@ def pack_bits_ref(bits: jnp.ndarray) -> jnp.ndarray:
 
 
 def _pack_kernel(b_ref, out_ref):
-    b = b_ref[...].astype(jnp.uint32)            # (32, block_rows, LANE)
-    j = jax.lax.broadcasted_iota(jnp.uint32, b.shape, 0)
+    b = b_ref[...]                               # (32, block_rows, LANE) int32
+    j = jax.lax.broadcasted_iota(jnp.int32, b.shape, 0)
     # disjoint powers of two per j: the integer sum is the bitwise OR
-    out_ref[...] = jnp.sum(b << (jnp.uint32(31) - j), axis=0,
-                           dtype=jnp.uint32)
+    out_ref[...] = jnp.sum(b << (31 - j), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -92,10 +93,10 @@ def pack_bits_words(
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec((32, block_rows, LANE), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         interpret=interpret,
     )(b3)
-    return out.reshape(-1)[:n_words]
+    return jax.lax.bitcast_convert_type(out.reshape(-1)[:n_words], jnp.uint32)
 
 
 def pack_bits_words_batched(

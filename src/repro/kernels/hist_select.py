@@ -46,12 +46,12 @@ P-client selection is ONE kernel launch.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.selection import (DEFAULT_CAP, NBINS, PASSES, bin_index,
                                   locate_bin, resolve_interpret)
@@ -68,36 +68,7 @@ __all__ = [
     "hist_topk_threshold_batched",
 ]
 
-_TPU_CHUNK_ROWS = 8  # compiled-mode one-hot chunk: 8*128 elems × 256 bins × 4B = 1 MiB
-
-
-def _block_hist(a, bin_idx, valid, *, bins: int, chunk_rows: int):
-    """(counts, sums) of one (rows, LANE) block via chunked one-hot matmuls."""
-    rows = a.shape[0]
-    assert chunk_rows >= rows or rows % chunk_rows == 0, (rows, chunk_rows)
-    bin_sent = jnp.where(valid, bin_idx, bins)  # padding -> no bin
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, bins), 1)
-
-    if chunk_rows >= rows:
-        oh = (bin_sent.reshape(-1, 1) == iota).astype(jnp.float32)
-        cnt = jnp.sum(oh, axis=0).reshape(1, bins)
-        sums = jnp.dot(a.reshape(1, -1), oh)
-        return cnt, sums
-
-    nchunks = rows // chunk_rows
-
-    def body(j, acc):
-        cacc, sacc = acc
-        ab = jax.lax.dynamic_slice_in_dim(a, j * chunk_rows, chunk_rows, 0)
-        bb = jax.lax.dynamic_slice_in_dim(bin_sent, j * chunk_rows,
-                                          chunk_rows, 0)
-        oh = (bb.reshape(-1, 1) == iota).astype(jnp.float32)
-        cacc = cacc + jnp.sum(oh, axis=0).reshape(1, bins)
-        sacc = sacc + jnp.dot(ab.reshape(1, -1), oh)
-        return cacc, sacc
-
-    zero = jnp.zeros((1, bins), jnp.float32)
-    return jax.lax.fori_loop(0, nchunks, body, (zero, zero))
+SUB = 8  # sublanes per chunk: the kernel bins one (8, LANE) tile at a time
 
 
 def magnitude_histogram(
@@ -120,28 +91,40 @@ def magnitude_histogram(
     return cnt[0], s[0]
 
 
-def _hist_kernel_batched(x_ref, scale_ref, cnt_ref, sum_ref,
-                         *, block_rows: int, n: int, bins: int,
-                         chunk_rows: int):
-    i = pl.program_id(1)
-    a = jnp.abs(x_ref[0].astype(jnp.float32))        # (block_rows, LANE)
-    scale = scale_ref[0, 0]
-
-    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    gidx = (i * block_rows + row) * LANE + col
-    valid = gidx < n
-
-    cnt, sums = _block_hist(a, bin_index(a, scale, bins), valid,
-                            bins=bins, chunk_rows=chunk_rows)
+def _hist_kernel_batched(x_ref, scale_ref, cnt_ref, sum_ref, cnt_acc,
+                         sum_acc, *, block_rows: int, n: int, bins: int):
+    c, i = pl.program_id(0), pl.program_id(1)
+    scale = scale_ref[c]
 
     @pl.when(i == 0)
     def _init():
-        cnt_ref[...] = jnp.zeros((1, bins), jnp.int32)
-        sum_ref[...] = jnp.zeros((1, bins), jnp.float32)
+        cnt_acc[...] = jnp.zeros(cnt_acc.shape, jnp.int32)
+        sum_acc[...] = jnp.zeros(sum_acc.shape, jnp.float32)
 
-    cnt_ref[...] += cnt.astype(jnp.int32)
-    sum_ref[...] += sums
+    # per-(bin, sublane, lane) partials: the one-hot of each (SUB, LANE) tile
+    # is added elementwise, so no reshape or cross-lane op runs per element
+    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (bins, SUB, LANE), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUB, LANE), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (SUB, LANE), 1)
+
+    def body(j, carry):
+        r0 = pl.multiple_of(j * SUB, SUB)
+        a = jnp.abs(x_ref[0, pl.ds(r0, SUB), :].astype(jnp.float32))
+        valid = (i * block_rows + r0 + row) * LANE + col < n
+        idx = jnp.where(valid, bin_index(a, scale, bins), bins)  # pad: no bin
+        oh = idx[None] == bin_iota
+        cnt_acc[...] += oh.astype(jnp.int32)
+        sum_acc[...] += jnp.where(oh, a[None], 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, block_rows // SUB, body, 0)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _emit():
+        cnt_ref[0] = jnp.sum(jnp.sum(cnt_acc[...], axis=1), axis=1,
+                             keepdims=True)
+        sum_ref[0] = jnp.sum(jnp.sum(sum_acc[...], axis=1), axis=1,
+                             keepdims=True)
 
 
 def magnitude_histogram_batched(
@@ -154,41 +137,40 @@ def magnitude_histogram_batched(
 ):
     """Batched histogram over a (clients, n) matrix -> (B, bins) each.
 
-    ``scale``: (B,) per-client ``bins / max|x_b|``.  One kernel launch with
-    grid ``(client, block)`` instead of a vmap of per-client launches.
+    ``scale``: (B,) per-client ``bins / max|x_b|``, read from SMEM.  One
+    kernel launch with grid ``(client, block)`` instead of a vmap of
+    per-client launches.
     """
     interpret = resolve_interpret(interpret)
     block_rows = resolve_block_rows(block_rows, interpret)
+    assert block_rows % SUB == 0, block_rows
     PASSES.record("histogram")
     b, n = x.shape
     x3 = pad_3d(x, block_rows)
     grid = (b, x3.shape[1] // block_rows)
-    s2 = scale.reshape(b, 1).astype(jnp.float32)
-    # compiled mode chunks the one-hot to bound VMEM; gcd keeps the chunk an
-    # exact divisor of block_rows so no trailing rows are ever dropped
-    chunk_rows = block_rows if interpret \
-        else math.gcd(block_rows, _TPU_CHUNK_ROWS)
 
     kernel = functools.partial(_hist_kernel_batched, block_rows=block_rows,
-                               n=n, bins=bins, chunk_rows=chunk_rows)
+                               n=n, bins=bins)
     cnt, s = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_rows, LANE), lambda c, i: (c, i, 0)),
-            pl.BlockSpec((1, 1), lambda c, i: (c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, bins), lambda c, i: (c, 0)),
-            pl.BlockSpec((1, bins), lambda c, i: (c, 0)),
+            pl.BlockSpec((1, bins, 1), lambda c, i: (c, 0, 0)),
+            pl.BlockSpec((1, bins, 1), lambda c, i: (c, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, bins), jnp.int32),
-            jax.ShapeDtypeStruct((b, bins), jnp.float32),
+            jax.ShapeDtypeStruct((b, bins, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, bins, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bins, SUB, LANE), jnp.int32),
+                        pltpu.VMEM((bins, SUB, LANE), jnp.float32)],
         interpret=interpret,
-    )(x3, s2)
-    return cnt, s
+    )(x3, scale.reshape(b).astype(jnp.float32))
+    return cnt[..., 0], s[..., 0]
 
 
 # ---------------------------------------------------------------------------

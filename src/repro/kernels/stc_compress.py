@@ -11,8 +11,8 @@ This kernel fuses everything into ONE pass: read ``carried`` once, write
 ``ΔW + A`` (already materialized by the k-selection step) straight through, so
 the delta/residual pair is never re-read and the add never recomputed.
 Inputs are tiled to (block_rows, 128) VMEM blocks; the threshold t and
-magnitude µ are scalar (1, 1) operands computed by the histogram selector in
-:mod:`.hist_select` (or the bisection fallback in :mod:`.topk_threshold`).
+magnitude µ are per-client scalars in SMEM, computed by the histogram selector
+in :mod:`.hist_select` (or the bisection fallback in :mod:`.topk_threshold`).
 
 ``stc_apply_batched`` adds a leading client axis: grid ``(client, block)``
 with per-client (t, µ) scalars, so compressing P participants is ONE kernel
@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._util import (LANE, PASSES, pad_3d, resolve_block_rows,
                     resolve_interpret)
@@ -54,11 +55,11 @@ def stc_apply(
 
 
 def _fused_kernel(c_ref, t_ref, mu_ref, tern_ref, res_ref,
-                          *, block_rows: int, n: int):
-    i = pl.program_id(1)                     # block index within the client
-    carried = c_ref[0].astype(jnp.float32)   # (block_rows, LANE)
-    t = t_ref[0, 0]
-    mu = mu_ref[0, 0]
+                  *, block_rows: int, n: int):
+    c, i = pl.program_id(0), pl.program_id(1)  # client, block within client
+    carried = c_ref[0].astype(jnp.float32)     # (block_rows, LANE)
+    t = t_ref[c]
+    mu = mu_ref[c]
 
     row = jax.lax.broadcasted_iota(jnp.int32, carried.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, carried.shape, 1)
@@ -90,8 +91,6 @@ def stc_apply_batched(
     b, n = carried.shape
     c3 = pad_3d(carried, block_rows)
     grid = (b, c3.shape[1] // block_rows)
-    t2 = thresh.reshape(b, 1).astype(jnp.float32)
-    mu2 = mu.reshape(b, 1).astype(jnp.float32)
 
     kernel = functools.partial(_fused_kernel, block_rows=block_rows,
                                n=n)
@@ -100,8 +99,8 @@ def stc_apply_batched(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_rows, LANE), lambda c, i: (c, i, 0)),
-            pl.BlockSpec((1, 1), lambda c, i: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c, i: (c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_rows, LANE), lambda c, i: (c, i, 0)),
@@ -112,5 +111,6 @@ def stc_apply_batched(
             jax.ShapeDtypeStruct(c3.shape, jnp.float32),
         ],
         interpret=interpret,
-    )(c3, t2, mu2)
+    )(c3, thresh.reshape(b).astype(jnp.float32),
+      mu.reshape(b).astype(jnp.float32))
     return tern.reshape(b, -1)[:, :n], res.reshape(b, -1)[:, :n]

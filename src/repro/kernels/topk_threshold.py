@@ -14,8 +14,8 @@ rare-case fallback of the single-pass histogram selector in
 
 The kernel tiles the (padded, reshaped to (M, 128)) input into VMEM blocks of
 ``(block_rows, 128)`` and accumulates scalar partials across the sequential
-TPU grid into a (1, 1) output block (same output block for every grid step —
-the canonical Pallas reduction pattern).
+TPU grid into (1, 1) SMEM outputs (same output for every grid step — the
+canonical Pallas reduction pattern; VMEM cannot hold a scalar store).
 """
 
 from __future__ import annotations
@@ -25,14 +25,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._util import (DEFAULT_BLOCK_ROWS, LANE, PASSES, pad_2d,
                     resolve_block_rows, resolve_interpret)
 
 __all__ = ["threshold_stats", "topk_threshold", "LANE", "DEFAULT_BLOCK_ROWS"]
-
-# back-compat alias: older call sites import the padder from this module
-_pad_2d = pad_2d
 
 
 def _stats_kernel(x_ref, t_ref, cnt_ref, sum_ref, *, block_rows: int, n: int):
@@ -86,11 +84,11 @@ def threshold_stats(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.int32),

@@ -22,9 +22,10 @@ Momentum defaults OFF per the paper's lesson (6) (stale client momentum harms
 non-iid + partial-participation training); pass momentum>0 to enable
 per-client buffers.
 
-Run as a script for a CPU demo on a debug mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PYTHONPATH=src python -m repro.launch.train --arch smollm-135m
+Run as a script for a demo on a mesh of the attached chips (one client per
+chip; on the CPU, ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+gives four virtual ones):
+    PYTHONPATH=src python -m repro.launch.train --arch smollm-135m
 """
 
 from __future__ import annotations
@@ -212,8 +213,9 @@ def batch_shardings(batch, mesh, global_batch: int):
 
 
 def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
-    """Returns ``train_step(state, batch) -> (state, metrics)`` wrapped in
-    shard_map over the client axes (auto axis: "model")."""
+    """Returns the jitted ``train_step(state, batch) -> (state, metrics)``:
+    shard_map over the client axes (auto axis: "model"), or the plain step on
+    a mesh without client axes."""
     ca = _client_axes(mesh)
     n_clients = math.prod(mesh.shape[a] for a in ca) if ca else 1
     numel = cfg.param_count()
@@ -342,7 +344,7 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
                     "train_step got mask/staleness but TrainConfig.masked is "
                     "False; rebuild the step with TrainConfig(masked=True)")
             return step_fn(state, batch, mask, staleness)
-        return single
+        return jax.jit(single)
 
     state_specs_in = {
         "params": P(), "step": P(),
@@ -375,33 +377,25 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
         args = (state, batch, mask, staleness) if tc.masked \
             else (state, batch)
         # NOTE: partial-manual shard_map must run through jit (the eager impl
-        # path mishandles check_vma=False with auto axes in jax 0.8).
-        if hasattr(jax, "shard_map"):
-            f = jax.shard_map(step_fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=outs,
-                              axis_names=set(ca), check_vma=False)
-        else:  # jax <= 0.4.x spelling: manual axes via the auto-complement
-            from jax.experimental.shard_map import shard_map
-            auto = frozenset(mesh.axis_names) - set(ca)
-            f = shard_map(step_fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=outs, check_rep=False,
-                          auto=auto)
+        # path mishandles check_vma=False with auto axes).
+        f = jax.shard_map(step_fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=outs, axis_names=set(ca), check_vma=False)
         return f(*args)
 
     return jax.jit(wrapped)
 
 
 # ---------------------------------------------------------------------------
-# CPU demo driver
+# demo driver
 # ---------------------------------------------------------------------------
 
 
 def main():
     import argparse
-    import numpy as np
     from repro.configs import get_smoke_config
     from repro.data import make_lm_tokens
-    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
@@ -415,30 +409,31 @@ def main():
                          "(default: one global flat selection)")
     args = ap.parse_args()
 
-    if len(jax.devices()) < 4:
-        raise SystemExit("run with XLA_FLAGS=--xla_force_host_platform_"
-                         "device_count=8 for the debug mesh")
-    mesh = make_debug_mesh(data=2, model=2)
+    use_compile_cache()
+    mesh = make_mesh()                   # every attached chip on "data"
+    n_clients = mesh.shape["data"]
     cfg = get_smoke_config(args.arch)
     tc = TrainConfig(protocol=args.protocol, lr=0.05, sparsity_up=1 / 50,
                      sparsity_down=1 / 50, measure_wire=args.measure_wire,
                      chunks=args.chunks)
-    state = init_train_state(cfg, tc, n_clients=2, key=jax.random.PRNGKey(0))
+    state = init_train_state(cfg, tc, n_clients=n_clients,
+                             key=jax.random.PRNGKey(0))
 
-    toks = make_lm_tokens(n_tokens=4 * 128 + 1, vocab=cfg.vocab_size)
-    batch = {"tokens": jnp.asarray(toks[:-1].reshape(4, 128)),
-             "labels": jnp.asarray(toks[1:].reshape(4, 128))}
+    b = 2 * n_clients
+    toks = make_lm_tokens(n_tokens=b * 128 + 1, vocab=cfg.vocab_size)
+    batch = {"tokens": jnp.asarray(toks[:-1].reshape(b, 128)),
+             "labels": jnp.asarray(toks[1:].reshape(b, 128))}
     if cfg.encoder is not None:
-        batch["frames"] = jnp.zeros((4, cfg.encoder.n_frames, cfg.d_model),
+        batch["frames"] = jnp.zeros((b, cfg.encoder.n_frames, cfg.d_model),
                                     jnp.float32)
     if cfg.n_prefix_tokens:
-        batch["prefix"] = jnp.zeros((4, cfg.n_prefix_tokens, cfg.d_model),
+        batch["prefix"] = jnp.zeros((b, cfg.n_prefix_tokens, cfg.d_model),
                                     jnp.float32)
+    state = jax.device_put(state, state_shardings(state, mesh))
+    batch = jax.device_put(batch, batch_shardings(batch, mesh, b))
 
     ledger = WireLedger(codec_for(tc), cfg.param_count())
-    # jax >= 0.8 spells the ambient mesh jax.set_mesh; 0.4.x enters the Mesh
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(cfg, mesh, tc)
         for i in range(args.steps):
             if tc.measure_wire:

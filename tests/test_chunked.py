@@ -13,10 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal deterministic fallback (see the stub)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (Codec, chunk_codec, chunk_spec_from_sizes,
                         chunk_spec_from_tree, get_stc_backend, make_protocol,

@@ -6,17 +6,7 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
-
-# The train_step uses partial-manual shard_map (manual client axes over an
-# auto "model" axis).  jax < 0.5 has no jax.shard_map and its
-# experimental shard_map's auto-subgroup support hard-crashes XLA
-# (CHECK sharding.IsManualSubgroup()), so these tests need a newer jax.
-_PARTIAL_MANUAL = hasattr(jax, "shard_map")
-pytestmark = pytest.mark.skipif(
-    not _PARTIAL_MANUAL,
-    reason="partial-manual shard_map unsupported on this jax version")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {**os.environ,
@@ -34,11 +24,11 @@ def test_train_step_loss_decreases_all_protocols():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, init_train_state, make_train_step
 from repro.data import make_lm_tokens
 
-mesh = make_debug_mesh(data=2, model=2)
+mesh = make_mesh(data=2, model=2)
 cfg = get_smoke_config("smollm-135m")
 toks = make_lm_tokens(n_tokens=4*128+1, vocab=cfg.vocab_size)
 batch = {"tokens": jnp.asarray(toks[:-1].reshape(4,128)),
@@ -70,11 +60,11 @@ def test_masked_step_drops_client_without_stalling():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, init_train_state, make_train_step
 from repro.models import lm_loss
 
-mesh = make_debug_mesh(data=2, model=2)
+mesh = make_mesh(data=2, model=2)
 cfg = get_smoke_config("qwen2-0.5b")
 tc = TrainConfig(protocol="baseline", lr=0.1, compute_dtype=jnp.float32,
                  masked=True)
@@ -134,12 +124,12 @@ def test_distributed_stc_matches_single_device_semantics():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, init_train_state, make_train_step
 from repro.core.distributed import stc_compress_tree, tree_add
 from repro.models import lm_loss
 
-mesh = make_debug_mesh(data=2, model=2)
+mesh = make_mesh(data=2, model=2)
 cfg = get_smoke_config("qwen2-0.5b")
 tc = TrainConfig(protocol="stc", lr=0.1, sparsity_up=1/20, sparsity_down=1/20,
                  compute_dtype=jnp.float32)

@@ -61,19 +61,17 @@ class TestHistogramKernel:
 
 class TestBlockHistChunking:
     def test_chunked_equals_single_shot(self):
-        """The compiled-mode (VMEM-bounded) chunked one-hot accumulation must
-        equal the interpret-mode single-shot block histogram."""
-        from repro.kernels.hist_select import _block_hist
-        rng = np.random.default_rng(9)
-        rows, lane, bins = 64, 128, 256
-        a = jnp.asarray(np.abs(rng.standard_normal((rows, lane))), jnp.float32)
-        idx = jnp.clip((a * 80.0).astype(jnp.int32), 0, bins - 1)
-        valid = jnp.asarray(rng.random((rows, lane)) < 0.9)
-        cnt_1, sum_1 = _block_hist(a, idx, valid, bins=bins, chunk_rows=rows)
-        cnt_c, sum_c = _block_hist(a, idx, valid, bins=bins, chunk_rows=8)
-        np.testing.assert_array_equal(np.asarray(cnt_1), np.asarray(cnt_c))
-        np.testing.assert_allclose(np.asarray(sum_1), np.asarray(sum_c),
-                                   rtol=1e-5)
+        """The kernel bins one (8, 128) tile at a time and carries its
+        partials across blocks: one tile per block, many tiles per block and
+        the whole input in one block must all equal the one-shot oracle."""
+        x = _rand(64 * 128 - 77, seed=9)
+        scale = jnp.float32(256.0) / jnp.max(jnp.abs(x))
+        cnt_r, sum_r = kref.magnitude_histogram_ref(x, scale)
+        for block_rows in (8, 16, 64):
+            cnt, sums = magnitude_histogram(x, scale, block_rows=block_rows)
+            np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_r))
+            np.testing.assert_allclose(np.asarray(sums), np.asarray(sum_r),
+                                       rtol=1e-5)
 
 
 class TestExactSelection:
@@ -256,6 +254,42 @@ class TestTreeForcedPaths:
         tern_j, stats_j = stc_compress(vec, p)
         got, _ = flatten_pytree(tern_t)
         assert int(st.nnz) == int(stats_j.nnz)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(tern_j),
+                                   atol=1e-6)
+
+
+class TestTreeChipBranch:
+    """The selection branch ``stc_compress_tree`` takes on a TPU, run here by
+    making ``resolve_interpret`` report the chip: the histogram sweep with
+    its exact refine at the default cap, and the bisection fallback when the
+    candidate bin overflows a small cap.  Both must find lax.top_k's k-th
+    magnitude."""
+
+    @pytest.fixture
+    def on_chip(self, monkeypatch):
+        import repro.core.distributed as dist
+        monkeypatch.setattr(dist, "resolve_interpret", lambda _: False)
+
+    def _tree(self, seed):
+        rng = np.random.default_rng(seed)
+        return {"w": jnp.asarray(rng.standard_normal((300, 200)), jnp.float32),
+                "b": jnp.asarray(rng.standard_normal(5000) * 3, jnp.float32)}
+
+    @pytest.mark.parametrize("cap", [8192, 8])
+    def test_matches_top_k(self, on_chip, cap):
+        tree = self._tree(cap)
+        p = 0.01
+        vec, _ = flatten_pytree(tree)
+        k = max(int(vec.size * p), 1)
+        PASSES.reset()
+        tern_t, st = stc_compress_tree(tree, p, cap=cap)
+        assert PASSES.counts == {"max": 1, "histogram": 1, "refine": 1}
+        want = jax.lax.top_k(jnp.abs(vec), k)[0][k - 1]
+        assert np.float32(st.thresh) == np.float32(want)
+        tern_j, stats_j = stc_compress(vec, p)
+        got, _ = flatten_pytree(tern_t)
+        assert int(st.nnz) == int(stats_j.nnz)
+        np.testing.assert_allclose(float(st.mu), float(stats_j.mu), rtol=1e-5)
         np.testing.assert_allclose(np.asarray(got), np.asarray(tern_j),
                                    atol=1e-6)
 

@@ -5,10 +5,7 @@ ledger cross-check (measured <= Eq. 13/15-style bound) in a real fed run."""
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal deterministic fallback (see the stub)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import UpdateCache, golomb, make_protocol, wire
 

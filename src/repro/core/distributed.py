@@ -43,6 +43,9 @@ class TreeStats(NamedTuple):
     numel: int
     mu: jnp.ndarray
     thresh: jnp.ndarray
+    # int32 1 when the candidate bin overflowed ``cap`` and the selection ran
+    # the bisection fallback, else 0: a runtime count of that branch
+    fallback: jnp.ndarray = np.int32(0)
 
 
 def tree_numel(tree) -> int:
@@ -158,12 +161,12 @@ def stc_compress_tree(tree, p: float, *, manual_axes=(), iters: int = 32,
     server stage when state is scattered); () when each caller holds the full
     (possibly GSPMD-sharded) tree.  ``iters`` only affects the bisection
     fallback taken when the candidate histogram bin overflows ``cap``.
+
+    The histogram sweep, the refine and the fallback run under the named
+    scopes ``histogram``, ``refine`` and ``fallback``, so a device trace
+    can time each; ``stats.fallback`` says whether the fallback ran.
     """
     numel = numel if numel is not None else tree_numel(tree)
-    if manual_axes:
-        # numel above counts only the local shard -- scale by the axis size
-        # is wrong for uneven shards; callers pass explicit numel instead.
-        pass
     k = max(int(numel * p), 1)
 
     if resolve_interpret(None) and k <= cap:
@@ -180,22 +183,24 @@ def stc_compress_tree(tree, p: float, *, manual_axes=(), iters: int = 32,
     scale = jnp.where(a_max > 0, jnp.float32(bins) / a_max, jnp.float32(0.0))
 
     PASSES.record("histogram")                                  # sweep 2
-    cnt, s = _tree_histogram(tree, scale, bins)
-    cnt = _psum(cnt, manual_axes)
-    s = _psum(s, manual_axes)
-    b, cnt_gt, sum_gt, cnt_b = locate_bin(cnt, s, k, bins)
+    with jax.named_scope("histogram"):
+        cnt, s = _tree_histogram(tree, scale, bins)
+        cnt = _psum(cnt, manual_axes)
+        s = _psum(s, manual_axes)
+        b, cnt_gt, sum_gt, cnt_b = locate_bin(cnt, s, k, bins)
     r = k - cnt_gt                                              # 1 <= r <= cnt_b
 
     PASSES.record("refine")                                     # sweep 3
-    cands = []
-    for leaf in jax.tree.leaves(tree):
-        a = jnp.abs(leaf.astype(jnp.float32)).reshape(-1)
-        in_bin = bin_index(a, scale, bins) == b
-        masked = jnp.where(in_bin, a, jnp.float32(-1.0))
-        cands.append(jax.lax.top_k(masked, min(cap, a.size))[0])
-    cands = jnp.concatenate(cands)
-    if manual_axes:
-        cands = jax.lax.all_gather(cands, manual_axes).reshape(-1)
+    with jax.named_scope("refine"):
+        cands = []
+        for leaf in jax.tree.leaves(tree):
+            a = jnp.abs(leaf.astype(jnp.float32)).reshape(-1)
+            in_bin = bin_index(a, scale, bins) == b
+            masked = jnp.where(in_bin, a, jnp.float32(-1.0))
+            cands.append(jax.lax.top_k(masked, min(cap, a.size))[0])
+        cands = jnp.concatenate(cands)
+        if manual_axes:
+            cands = jax.lax.all_gather(cands, manual_axes).reshape(-1)
 
     def _exact(_):
         srt = jnp.sort(cands)[::-1]              # descending, ≤ L·cap values
@@ -205,14 +210,17 @@ def stc_compress_tree(tree, p: float, *, manual_axes=(), iters: int = 32,
                 sum_gt + jnp.sum(jnp.where(ge, cands, 0.0)))
 
     def _fallback(_):
-        return _bisect_threshold(tree, k, a_max, manual_axes, iters)
+        with jax.named_scope("fallback"):
+            return _bisect_threshold(tree, k, a_max, manual_axes, iters)
 
-    thresh, cnt_tot, sum_tot = jax.lax.cond(cnt_b > cap, _fallback, _exact,
-                                            None)
-    return _finish_tree(tree, thresh, cnt_tot, sum_tot, numel)
+    overflow = cnt_b > cap
+    thresh, cnt_tot, sum_tot = jax.lax.cond(overflow, _fallback, _exact, None)
+    return _finish_tree(tree, thresh, cnt_tot, sum_tot, numel,
+                        fallback=overflow.astype(jnp.int32))
 
 
-def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel):
+def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel,
+                 fallback=np.int32(0)):
     """µ + per-leaf ternarization from the selected (thresh, count, sum)."""
     mu = sum_tot / jnp.maximum(cnt_tot, 1).astype(jnp.float32)
 
@@ -222,7 +230,8 @@ def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel):
         return jnp.where(m, mu * jnp.sign(xf), 0.0).astype(x.dtype)
 
     tern = jax.tree.map(tern_leaf, tree)
-    return tern, TreeStats(nnz=cnt_tot, numel=numel, mu=mu, thresh=thresh)
+    return tern, TreeStats(nnz=cnt_tot, numel=numel, mu=mu, thresh=thresh,
+                           fallback=fallback)
 
 
 def stc_compress_tree_chunked(tree, p: float, chunk_size: int, *,

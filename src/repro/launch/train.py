@@ -215,7 +215,25 @@ def batch_shardings(batch, mesh, global_batch: int):
 def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
     """Returns the jitted ``train_step(state, batch) -> (state, metrics)``:
     shard_map over the client axes (auto axis: "model"), or the plain step on
-    a mesh without client axes."""
+    a mesh without client axes.
+
+    The round's phases run under the named scopes ``local_step``, ``encode``,
+    ``exchange`` and ``decode``, so a device trace can time each.  The params
+    update has none: on the chip it fuses into the decode's last pass over
+    each leaf, so it has no device op of its own.
+
+    ``metrics`` holds the round's scalars, those the codec reports:
+
+    * ``loss``: the local loss, mean over the clients;
+    * ``nnz_up``: nonzeros of one client's upload message (not summed: the
+      replicated out spec returns one block's value);
+    * ``nnz_down``: nonzeros of the server's downstream message (the same on
+      every block);
+    * ``fallback_up``: how many of the clients' upload selections ran the
+      bisection fallback (summed over the client axes);
+    * ``fallback_down``: 1 if the server's selection ran it, else 0 (counted
+      once: decode runs identically on every block).
+    """
     ca = _client_axes(mesh)
     n_clients = math.prod(mesh.shape[a] for a in ca) if ca else 1
     numel = cfg.param_count()
@@ -276,7 +294,8 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
         if "momentum" in state:
             mom = jax.tree.map(lambda x: x[0], state["momentum"])
 
-        delta, mom, loss = local_delta(params, mom, batch)
+        with jax.named_scope("local_step"):
+            delta, mom, loss = local_delta(params, mom, batch)
         metrics = {"loss": jax.lax.pmean(loss, ca) if ca else loss}
         new_state = dict(state)
         new_state["step"] = state["step"] + 1
@@ -294,8 +313,12 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
         # ---- the entire protocol: three codec calls, zero dispatch ---------
         cres = (jax.tree.map(lambda x: x[0], state["client_res"])
                 if "client_res" in state else None)
-        msg, new_cres, m_up = codec.tree_encode(delta, cres, numel=numel,
-                                                iters=tc.stc_iters)
+        with jax.named_scope("encode"):
+            msg, new_cres, m_up = codec.tree_encode(delta, cres, numel=numel,
+                                                    iters=tc.stc_iters)
+        if ca and "fallback_up" in m_up:
+            m_up = dict(m_up, fallback_up=jax.lax.psum(m_up["fallback_up"],
+                                                       ca))
         if "client_res" in state:
             if arrived is not None:
                 new_cres = jax.tree.map(
@@ -303,24 +326,28 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
                     new_cres, state["client_res"])
             new_state["client_res"] = jax.tree.map(lambda x: x[None], new_cres)
         # ---- upload: the ONLY protocol-level collective --------------------
-        combined = codec.tree_reduce(msg, ca, n_clients, mask=mask,
-                                     staleness=staleness)
-        global_delta, new_sres, m_down = codec.tree_decode(
-            combined, state.get("server_res"), numel=numel, iters=tc.stc_iters)
-        if mask is not None:
-            # zero-arrival step: the server must not move either -- without
-            # this gate a stateful codec (stc) would still drain its server
-            # residual into a parameter update off the all-zero combined tree
-            total = jnp.sum(mask)
-            if ca:
-                total = jax.lax.psum(total, ca)
-            any_arrived = total > 0
-            global_delta = jax.tree.map(
-                lambda d: jnp.where(any_arrived, d, 0.0), global_delta)
-            if new_sres is not None:
-                new_sres = jax.tree.map(
-                    lambda new, old: jnp.where(any_arrived, new, old),
-                    new_sres, state.get("server_res"))
+        with jax.named_scope("exchange"):
+            combined = codec.tree_reduce(msg, ca, n_clients, mask=mask,
+                                         staleness=staleness)
+        with jax.named_scope("decode"):
+            global_delta, new_sres, m_down = codec.tree_decode(
+                combined, state.get("server_res"), numel=numel,
+                iters=tc.stc_iters)
+            if mask is not None:
+                # zero-arrival step: the server must not move either --
+                # without this gate a stateful codec (stc) would still drain
+                # its server residual into a parameter update off the
+                # all-zero combined tree
+                total = jnp.sum(mask)
+                if ca:
+                    total = jax.lax.psum(total, ca)
+                any_arrived = total > 0
+                global_delta = jax.tree.map(
+                    lambda d: jnp.where(any_arrived, d, 0.0), global_delta)
+                if new_sres is not None:
+                    new_sres = jax.tree.map(
+                        lambda new, old: jnp.where(any_arrived, new, old),
+                        new_sres, state.get("server_res"))
         if "server_res" in state:
             new_state["server_res"] = new_sres
         metrics.update(m_up)

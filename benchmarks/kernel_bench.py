@@ -12,7 +12,8 @@ Rows (n = flat update length):
                        the histogram kernel itself only pays off on TPU)
   stc_hist_batch8   -- batched (client, block)-grid path over 8 clients of
                        the SAME n; TOTAL launch time, /8 for per-client
-  stc_tree          -- no-flatten tree path (histogram selector)
+  stc_tree          -- no-flatten tree path (count bisection past the
+                       small-k shortcut)
 """
 
 from __future__ import annotations

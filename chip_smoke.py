@@ -12,7 +12,7 @@ fixed batch of 8 x 2048 tokens for 5 rounds.  It prints the compile time
 (set-up), each round's time after ``block_until_ready``, the device's
 ``peak_bytes_in_use`` and every round's loss, and checks that the losses are
 finite and fall, that every round uploads at least k = floor(numel / 400)
-entries, and that on round 1's delta the chip's histogram selection finds
+entries, and that on round 1's delta the chip's count selection finds
 exactly the threshold ``jax.lax.top_k`` finds.
 
 Phase B runs the flat server path (``repro.fed.FederatedTrainer``, fused
@@ -174,7 +174,7 @@ def phase_model_round(cfg, *, batch: int, seq: int, rounds: int, lr: float,
 
     t_sel, t_ref, nnz1 = [np.asarray(v) for v in
                           progs["round1_thresholds"](state["params"], data)]
-    log(phase="A", what="round1_threshold", histogram=t_sel, top_k=t_ref,
+    log(phase="A", what="round1_threshold", select=t_sel, top_k=t_ref,
         nnz=int(nnz1), k=k)
     check(t_sel == t_ref, f"round-1 threshold {t_sel!r} != top_k {t_ref!r}")
 

@@ -2,21 +2,20 @@
 
 Global top-k over the whole model == per-leaf masking with ONE global
 magnitude threshold, and µ == the global mean magnitude of kept entries.
-The threshold is found by the same single-pass histogram selection as
-:mod:`repro.kernels.hist_select`, but applied leaf-by-leaf: ONE sweep over
-the leaves accumulates a 256-bin (count, Σ|x|) histogram, a cumulative sum
-locates the k-th bin, and one gather pass over the candidate bin reads the
-exact k-th magnitude — replacing the old 32-iteration bisection fori_loop
-(32 full sweeps over every leaf) with ≤3 sweeps.  The result is *identical*
-to flattening-and-sorting, but touches every leaf in place: no concatenation,
-no resharding, no all-gather of the parameter vector.
+The threshold is found by counting: a max sweep bounds the magnitudes, a
+bisection on the threshold counts ``|x| >= mid`` over every leaf ``iters``
+times (one sweep each), and a final sweep takes the count, the kept sum and
+the least kept magnitude: the k-th magnitude once the bisection has
+separated it from the next smaller one, which 32 halvings do on the float32
+trees of the tests.  Each sweep is a streaming reduction over the leaves in
+place: no concatenation, no resharding, no all-gather of the parameter
+vector, no scatter and no sort.
 
 Reductions over the tensor-parallel ("model") axis happen automatically via
 GSPMD (jnp.sum of a sharded leaf is a global sum); reductions over manual
-(shard_map) axes are explicit: the per-bin histogram vectors are ``psum``-ed
-and the (tiny, ≤``cap``) candidate gather is ``all_gather``-ed.  On
-pathological inputs that overflow the candidate capacity the old bisection
-loop runs as an exactness fallback under ``lax.cond``.
+(shard_map) axes are explicit: each sweep's scalars are ``psum``-ed (the
+least kept magnitude ``pmin``-ed).  Off the TPU, a k no larger than ``cap``
+takes a per-leaf top-k shortcut instead (:func:`_direct_tree_select`).
 
 This module is the distributed twin of core.compression / kernels.ops, and is
 oracle-checked against them in tests.
@@ -30,8 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.selection import (DEFAULT_CAP, NBINS, PASSES, bin_index,
-                                  locate_bin, resolve_interpret)
+from repro.core.selection import DEFAULT_CAP, PASSES, resolve_interpret
 
 __all__ = ["TreeStats", "tree_numel", "stc_compress_tree",
            "stc_compress_tree_chunked", "ternary_quantize_tree",
@@ -43,9 +41,6 @@ class TreeStats(NamedTuple):
     numel: int
     mu: jnp.ndarray
     thresh: jnp.ndarray
-    # int32 1 when the candidate bin overflowed ``cap`` and the selection ran
-    # the bisection fallback, else 0: a runtime count of that branch
-    fallback: jnp.ndarray = np.int32(0)
 
 
 def tree_numel(tree) -> int:
@@ -69,27 +64,18 @@ def _pmax(x, manual_axes):
 
 
 def _count_and_sum(tree, t):
-    """(#|x|>=t, Σ|x| over that set) across all leaves (one sweep)."""
+    """(#|x|>=t, Σ|x| and least |x| over that set) across all leaves (one
+    sweep; a caller that drops the sum or the least lets XLA drop it)."""
     cnt = jnp.zeros((), jnp.int32)
     s = jnp.zeros((), jnp.float32)
+    least = jnp.float32(jnp.inf)
     for leaf in jax.tree.leaves(tree):
         a = jnp.abs(leaf.astype(jnp.float32))
         m = a >= t
         cnt = cnt + jnp.sum(m.astype(jnp.int32))
         s = s + jnp.sum(jnp.where(m, a, 0.0))
-    return cnt, s
-
-
-def _tree_histogram(tree, scale, bins):
-    """ONE sweep over the leaves -> per-bin (count, Σ|x|) vectors."""
-    cnt = jnp.zeros((bins,), jnp.int32)
-    s = jnp.zeros((bins,), jnp.float32)
-    for leaf in jax.tree.leaves(tree):
-        a = jnp.abs(leaf.astype(jnp.float32)).reshape(-1)
-        idx = bin_index(a, scale, bins)
-        cnt = cnt + jnp.bincount(idx, length=bins).astype(jnp.int32)
-        s = s + jnp.bincount(idx, weights=a, length=bins).astype(jnp.float32)
-    return cnt, s
+        least = jnp.minimum(least, jnp.min(jnp.where(m, a, jnp.inf)))
+    return cnt, s, least
 
 
 def _direct_tree_select(tree, k, cap, manual_axes):
@@ -128,43 +114,46 @@ def _direct_tree_select(tree, k, cap, manual_axes):
                 jnp.sum(jnp.where(ge, cands, 0.0)))
 
     def _tie_spill(_):                                         # rare sweep 2
-        cnt, s = _count_and_sum(tree, v)
+        cnt, s, _ = _count_and_sum(tree, v)
         return v, _psum(cnt, manual_axes), _psum(s, manual_axes)
 
     return jax.lax.cond(spill, _tie_spill, _from_gather, None)
 
 
 def _bisect_threshold(tree, k, a_max, manual_axes, iters):
-    """Old 32-sweep bisection; kept as the rare-case exactness fallback."""
+    """``iters`` count sweeps bisecting for the k-th magnitude, then one
+    sweep for (least kept magnitude, count, Σ|x|) at the bisection's
+    lower end, which keeps at least k values."""
     hi0 = a_max * jnp.float32(1.0 + 1e-6) + jnp.float32(1e-30)
     lo0 = jnp.float32(0.0)
 
     def body(_, carry):
         lo, hi = carry
         mid = 0.5 * (lo + hi)
-        cnt, _ = _count_and_sum(tree, mid)
+        cnt, _, _ = _count_and_sum(tree, mid)
         cnt = _psum(cnt, manual_axes)
         keep = cnt >= k
         return jnp.where(keep, mid, lo), jnp.where(keep, hi, mid)
 
     lo, _ = jax.lax.fori_loop(0, iters, body, (lo0, hi0))
-    cnt, s = _count_and_sum(tree, lo)
-    return lo, _psum(cnt, manual_axes), _psum(s, manual_axes)
+    cnt, s, least = _count_and_sum(tree, lo)
+    least = jax.lax.pmin(least, manual_axes) if manual_axes else least
+    return least, _psum(cnt, manual_axes), _psum(s, manual_axes)
 
 
 def stc_compress_tree(tree, p: float, *, manual_axes=(), iters: int = 32,
-                      numel: int | None = None, bins: int = NBINS,
-                      cap: int = DEFAULT_CAP):
+                      numel: int | None = None, cap: int = DEFAULT_CAP):
     """STC over a pytree: returns (ternary_tree, stats).
 
     ``manual_axes``: shard_map axis names the leaves are *sharded over* (the
     server stage when state is scattered); () when each caller holds the full
-    (possibly GSPMD-sharded) tree.  ``iters`` only affects the bisection
-    fallback taken when the candidate histogram bin overflows ``cap``.
+    (possibly GSPMD-sharded) tree.
 
-    The histogram sweep, the refine and the fallback run under the named
-    scopes ``histogram``, ``refine`` and ``fallback``, so a device trace
-    can time each; ``stats.fallback`` says whether the fallback ran.
+    The selection counts (module docstring): a max sweep, ``iters`` count
+    sweeps of the bisection and a final sweep, all under the named scope
+    ``select`` so that a device trace can time it.  ``stats.thresh`` is the
+    least kept magnitude, so ``|x| >= thresh`` keeps what the count kept.
+    Off the TPU, ``k <= cap`` takes the per-leaf top-k shortcut instead.
     """
     numel = numel if numel is not None else tree_numel(tree)
     k = max(int(numel * p), 1)
@@ -175,52 +164,20 @@ def stc_compress_tree(tree, p: float, *, manual_axes=(), iters: int = 32,
                                                        manual_axes)
         return _finish_tree(tree, thresh, cnt_tot, sum_tot, numel)
 
-    PASSES.record("max")                                        # sweep 1
-    a_max = jnp.zeros((), jnp.float32)
-    for leaf in jax.tree.leaves(tree):
-        a_max = jnp.maximum(a_max, jnp.max(jnp.abs(leaf.astype(jnp.float32))))
-    a_max = _pmax(a_max, manual_axes)
-    scale = jnp.where(a_max > 0, jnp.float32(bins) / a_max, jnp.float32(0.0))
-
-    PASSES.record("histogram")                                  # sweep 2
-    with jax.named_scope("histogram"):
-        cnt, s = _tree_histogram(tree, scale, bins)
-        cnt = _psum(cnt, manual_axes)
-        s = _psum(s, manual_axes)
-        b, cnt_gt, sum_gt, cnt_b = locate_bin(cnt, s, k, bins)
-    r = k - cnt_gt                                              # 1 <= r <= cnt_b
-
-    PASSES.record("refine")                                     # sweep 3
-    with jax.named_scope("refine"):
-        cands = []
+    PASSES.record("max")
+    PASSES.record("count", iters + 1)
+    with jax.named_scope("select"):
+        a_max = jnp.zeros((), jnp.float32)
         for leaf in jax.tree.leaves(tree):
-            a = jnp.abs(leaf.astype(jnp.float32)).reshape(-1)
-            in_bin = bin_index(a, scale, bins) == b
-            masked = jnp.where(in_bin, a, jnp.float32(-1.0))
-            cands.append(jax.lax.top_k(masked, min(cap, a.size))[0])
-        cands = jnp.concatenate(cands)
-        if manual_axes:
-            cands = jax.lax.all_gather(cands, manual_axes).reshape(-1)
-
-    def _exact(_):
-        srt = jnp.sort(cands)[::-1]              # descending, ≤ L·cap values
-        v = jnp.take(srt, r - 1, mode="clip")
-        ge = (cands >= 0.0) & (cands >= v)
-        return (v, cnt_gt + jnp.sum(ge.astype(jnp.int32)),
-                sum_gt + jnp.sum(jnp.where(ge, cands, 0.0)))
-
-    def _fallback(_):
-        with jax.named_scope("fallback"):
-            return _bisect_threshold(tree, k, a_max, manual_axes, iters)
-
-    overflow = cnt_b > cap
-    thresh, cnt_tot, sum_tot = jax.lax.cond(overflow, _fallback, _exact, None)
-    return _finish_tree(tree, thresh, cnt_tot, sum_tot, numel,
-                        fallback=overflow.astype(jnp.int32))
+            a_max = jnp.maximum(a_max,
+                                jnp.max(jnp.abs(leaf.astype(jnp.float32))))
+        a_max = _pmax(a_max, manual_axes)
+        thresh, cnt_tot, sum_tot = _bisect_threshold(tree, k, a_max,
+                                                     manual_axes, iters)
+    return _finish_tree(tree, thresh, cnt_tot, sum_tot, numel)
 
 
-def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel,
-                 fallback=np.int32(0)):
+def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel):
     """µ + per-leaf ternarization from the selected (thresh, count, sum)."""
     mu = sum_tot / jnp.maximum(cnt_tot, 1).astype(jnp.float32)
 
@@ -230,8 +187,7 @@ def _finish_tree(tree, thresh, cnt_tot, sum_tot, numel,
         return jnp.where(m, mu * jnp.sign(xf), 0.0).astype(x.dtype)
 
     tern = jax.tree.map(tern_leaf, tree)
-    return tern, TreeStats(nnz=cnt_tot, numel=numel, mu=mu, thresh=thresh,
-                           fallback=fallback)
+    return tern, TreeStats(nnz=cnt_tot, numel=numel, mu=mu, thresh=thresh)
 
 
 def stc_compress_tree_chunked(tree, p: float, chunk_size: int, *,

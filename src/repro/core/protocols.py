@@ -837,7 +837,7 @@ class TopKCodec(_ErrorFeedbackMixin, Codec):
         msg = jax.tree.map(
             lambda x: jnp.where(jnp.abs(x) >= st.thresh, x, 0.0), carried)
         new_res = jax.tree.map(lambda c, t: c - t, carried, msg)
-        return msg, new_res, {"nnz_up": st.nnz, "fallback_up": st.fallback}
+        return msg, new_res, {"nnz_up": st.nnz}
 
 
 @register_protocol
@@ -1057,7 +1057,7 @@ class StcCodec(_ErrorFeedbackMixin, Codec):
             tern, st = stc_compress_tree(carried, self.sparsity_up,
                                          numel=numel, iters=iters)
         new_res = jax.tree.map(lambda c, t: c - t, carried, tern)
-        return tern, new_res, {"nnz_up": st.nnz, "fallback_up": st.fallback}
+        return tern, new_res, {"nnz_up": st.nnz}
 
     def tree_decode(self, combined, residual, *, numel, iters=32):
         from .distributed import (stc_compress_tree,
@@ -1071,8 +1071,7 @@ class StcCodec(_ErrorFeedbackMixin, Codec):
             down, st = stc_compress_tree(carried, self.sparsity_down,
                                          numel=numel, iters=iters)
         new_res = jax.tree.map(lambda c, t: c - t, carried, down)
-        return down, new_res, {"nnz_down": st.nnz,
-                               "fallback_down": st.fallback}
+        return down, new_res, {"nnz_down": st.nnz}
 
 
 @register_protocol

@@ -1,7 +1,8 @@
 """Pure-jnp building blocks of histogram k-selection, shared across layers.
 
-These helpers are used both by the Pallas kernels (:mod:`repro.kernels`) and
-by the tree-level compressor (:mod:`repro.core.distributed`).  They live here
+These helpers are used by the Pallas kernels (:mod:`repro.kernels`), and
+``DEFAULT_CAP``, ``PASSES`` and ``resolve_interpret`` also by the tree-level
+compressor (:mod:`repro.core.distributed`).  They live here
 — below the kernels — so that core modules never import
 ``jax.experimental.pallas``: the layering is kernels -> core, never the
 reverse (see the lazy "kernel" backend lookup in :mod:`.compression`).
@@ -9,7 +10,7 @@ reverse (see the lazy "kernel" backend lookup in :mod:`.compression`).
 * ``bin_index`` / ``locate_bin`` -- the 256-bin linear magnitude binning and
   the cumulative-sum bin/rank search of the histogram selector.  The binning
   expression MUST stay bit-identical everywhere it is evaluated (histogram
-  kernel, refinement pass, tree sweep), so there is exactly one definition.
+  kernel, refinement pass), so there is exactly one definition.
 * ``resolve_interpret`` -- backend autodetect for the kernels' ``interpret``
   flag: compiled on a TPU, interpreted on the CPU backend, and an error on
   any other backend (a kernel never silently interprets off the CPU).
@@ -50,7 +51,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 def bin_index(a: jnp.ndarray, scale: jnp.ndarray, bins: int) -> jnp.ndarray:
     """Linear magnitude binning; MUST be bit-identical everywhere it is used
-    (histogram kernel, refinement pass, tree path)."""
+    (histogram kernel, refinement pass)."""
     return jnp.clip((a * scale).astype(jnp.int32), 0, bins - 1)
 
 

@@ -228,11 +228,7 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
     * ``nnz_up``: nonzeros of one client's upload message (not summed: the
       replicated out spec returns one block's value);
     * ``nnz_down``: nonzeros of the server's downstream message (the same on
-      every block);
-    * ``fallback_up``: how many of the clients' upload selections ran the
-      bisection fallback (summed over the client axes);
-    * ``fallback_down``: 1 if the server's selection ran it, else 0 (counted
-      once: decode runs identically on every block).
+      every block).
     """
     ca = _client_axes(mesh)
     n_clients = math.prod(mesh.shape[a] for a in ca) if ca else 1
@@ -316,9 +312,6 @@ def make_train_step(cfg: ModelConfig, mesh, tc: TrainConfig):
         with jax.named_scope("encode"):
             msg, new_cres, m_up = codec.tree_encode(delta, cres, numel=numel,
                                                     iters=tc.stc_iters)
-        if ca and "fallback_up" in m_up:
-            m_up = dict(m_up, fallback_up=jax.lax.psum(m_up["fallback_up"],
-                                                       ca))
         if "client_res" in state:
             if arrived is not None:
                 new_cres = jax.tree.map(

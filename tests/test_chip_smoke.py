@@ -20,7 +20,7 @@ from repro.configs import get_smoke_config  # noqa: E402
 @pytest.fixture
 def chip_selection_branch(monkeypatch):
     """Make ``stc_compress_tree`` take the branch it takes on a TPU
-    (histogram + refine) instead of the CPU's direct top-k shortcut."""
+    (the count bisection) instead of the CPU's direct top-k shortcut."""
     import repro.core.distributed as dist
     monkeypatch.setattr(dist, "resolve_interpret", lambda _: False)
 

@@ -218,9 +218,9 @@ class TestStreamingPassBudget:
 
 
 class TestTreeForcedPaths:
-    """On CPU every default-cap tree call takes the small-k shortcut; force
-    the TPU-route branches (histogram sweep + refine, bisection fallback)
-    with a small ``cap`` so they stay covered."""
+    """On CPU a default-cap tree call takes the small-k shortcut; a ``cap``
+    below k skips it, so the count route the TPU always takes (max sweep,
+    ``iters`` count sweeps, one final count) stays covered."""
 
     def _tree(self, seed=21):
         rng = np.random.default_rng(seed)
@@ -230,13 +230,12 @@ class TestTreeForcedPaths:
         }
 
     def test_histogram_refine_branch(self):
-        """cap < k skips the shortcut; the candidate bin (~n/256 · density)
-        still fits the gather, so histogram + exact refine runs."""
+        """cap < k skips the shortcut: the count route runs and is exact."""
         tree = self._tree()
         p = 0.02                                  # k = 2020 > cap
         PASSES.reset()
         tern_t, st = stc_compress_tree(tree, p, cap=1000)
-        assert PASSES.counts == {"max": 1, "histogram": 1, "refine": 1}
+        assert PASSES.counts == {"max": 1, "count": 33}
         vec, _ = flatten_pytree(tree)
         tern_j, stats_j = stc_compress(vec, p)
         got, _ = flatten_pytree(tern_t)
@@ -246,10 +245,12 @@ class TestTreeForcedPaths:
                                    atol=1e-6)
 
     def test_bisection_fallback_branch(self):
-        """cap tiny -> candidate bin overflows the gather -> bisection."""
+        """cap tiny: the same count route, exact."""
         tree = self._tree(22)
         p = 0.02
+        PASSES.reset()
         tern_t, st = stc_compress_tree(tree, p, cap=8)
+        assert PASSES.counts == {"max": 1, "count": 33}
         vec, _ = flatten_pytree(tree)
         tern_j, stats_j = stc_compress(vec, p)
         got, _ = flatten_pytree(tern_t)
@@ -260,10 +261,8 @@ class TestTreeForcedPaths:
 
 class TestTreeChipBranch:
     """The selection branch ``stc_compress_tree`` takes on a TPU, run here by
-    making ``resolve_interpret`` report the chip: the histogram sweep with
-    its exact refine at the default cap, and the bisection fallback when the
-    candidate bin overflows a small cap.  Both must find lax.top_k's k-th
-    magnitude."""
+    making ``resolve_interpret`` report the chip: the count route, whatever
+    the cap.  It must find lax.top_k's k-th magnitude."""
 
     @pytest.fixture
     def on_chip(self, monkeypatch):
@@ -275,15 +274,15 @@ class TestTreeChipBranch:
         return {"w": jnp.asarray(rng.standard_normal((300, 200)), jnp.float32),
                 "b": jnp.asarray(rng.standard_normal(5000) * 3, jnp.float32)}
 
-    @pytest.mark.parametrize("cap", [8192, 8])
-    def test_matches_top_k(self, on_chip, cap):
-        tree = self._tree(cap)
+    @pytest.mark.parametrize("seed", [8192, 8])
+    def test_matches_top_k(self, on_chip, seed):
+        tree = self._tree(seed)
         p = 0.01
         vec, _ = flatten_pytree(tree)
         k = max(int(vec.size * p), 1)
         PASSES.reset()
-        tern_t, st = stc_compress_tree(tree, p, cap=cap)
-        assert PASSES.counts == {"max": 1, "histogram": 1, "refine": 1}
+        tern_t, st = stc_compress_tree(tree, p)
+        assert PASSES.counts == {"max": 1, "count": 33}
         want = jax.lax.top_k(jnp.abs(vec), k)[0][k - 1]
         assert np.float32(st.thresh) == np.float32(want)
         tern_j, stats_j = stc_compress(vec, p)

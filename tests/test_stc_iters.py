@@ -1,9 +1,9 @@
 """k-selection accuracy contracts on the tree path.
 
-The histogram selector is exact, so ``iters`` only matters for the bisection
-fallback — force that route (tiny ``cap`` overflows the refinement gather) to
-keep the §Perf A3 contract tested: 12 rounds keep the selected count within
-1% of k on Gaussian-like updates; 32 rounds are exact.
+Past the CPU's small-k shortcut (k > ``cap``, and always on the TPU) the
+tree selection is a bisection of ``iters`` count sweeps, so ``iters`` sets
+its accuracy (the §Perf A3 contract): 12 rounds keep the selected count
+within 1% of k on Gaussian-like updates; 32 rounds are exact.
 """
 
 import jax.numpy as jnp
@@ -24,8 +24,8 @@ def test_bisection_fallback_iteration_accuracy():
     rng = np.random.default_rng(0)
     tree = {"w": jnp.asarray(rng.standard_normal(500_000), jnp.float32)}
     k = max(int(500_000 / 400), 1)
-    # cap=8 < k routes to the histogram path and overflows the candidate
-    # bin, exercising the bisection fallback with the given iters budget
+    # k = 1250 <= the default cap would take the CPU's top-k shortcut;
+    # cap=8 routes to the count bisection with the given iters budget
     _, st32 = stc_compress_tree(tree, 1 / 400, iters=32, cap=8)
     _, st12 = stc_compress_tree(tree, 1 / 400, iters=12, cap=8)
     assert int(st32.nnz) == k

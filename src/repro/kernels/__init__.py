@@ -18,6 +18,9 @@
   into its 32 MSB-first bits plus a fused per-word zero count (the seed of
   the decoder's run-length prefix scan), the device half of the ``"kernel"``
   wire DECODE backend.
+* ``attention``      -- causal GQA self-attention as a VMEM-tiled flash
+  kernel (the splash kernel that ships with JAX, skipping the tiles above the
+  diagonal), the local step's attention on the TPU.
 * ``ops``            -- jit'd public wrappers; ``ref`` -- pure-jnp oracles.
 
 All entry points take ``interpret: bool | None = None`` and autodetect the

@@ -3,9 +3,13 @@ memory-efficient chunked online-softmax, and single-token decode with KV cache.
 
 The chunked formulation (lax.scan over KV chunks with an online softmax) keeps
 the materialized score block at (B, KV, rep, Sq, C) instead of (B, H, Sq, Skv),
-which is what lets the 4k/32k dry-runs fit HBM without a handwritten flash
-kernel -- and it lowers on any backend (the dry-run compiles on CPU, where a
-Mosaic kernel would not).
+which is what lets the 4k/32k dry-runs fit HBM -- and it lowers on any backend
+(the dry-run compiles on CPU, where a Mosaic kernel would not).  On the TPU,
+``_attention`` runs causal bf16 self-attention at a length that is a multiple
+of 128, with one head size for q, k and v, as the Pallas flash kernel of
+``repro.kernels.attention``; every other shape keeps the jnp scan.  The whole
+of ``_attention`` runs under the scope ``attention``, the kernel under
+``attention_kernel`` inside it.
 """
 
 from __future__ import annotations
@@ -14,6 +18,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.attention import causal_gqa_attention, fits_mesh
 
 from .flash import flash_attention
 from .layers import apply_rope, dense_init, rope_freqs
@@ -34,12 +40,31 @@ ATTN_IMPL = "flash"
 DECODE_SHARD_HINT = None
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _takes_kernel(q, k, v, causal, window) -> bool:
+    """Causal bf16 self-attention on the TPU at a 128-multiple length with
+    one head size for q, k and v, where no mesh axis of size > 1 is left to
+    the partitioner: what the Pallas flash kernel runs."""
+    s, hd = q.shape[1], q.shape[3]
+    return (_on_tpu() and causal and window == 0 and k.shape[1] == s
+            and s % 128 == 0 and v.shape[3] == hd
+            and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
+            and fits_mesh())
+
+
 def _attention(q, k, v, *, causal, window=0, chunk=1024, impl=None):
     impl = impl or ATTN_IMPL
-    if impl == "flash":
+    with jax.named_scope("attention"):
+        if impl != "flash":
+            return chunked_attention(q, k, v, causal=causal, window=window,
+                                     chunk=chunk)
+        if _takes_kernel(q, k, v, causal, window):
+            with jax.named_scope("attention_kernel"):
+                return causal_gqa_attention(q, k, v)
         return flash_attention(q, k, v, causal, window, chunk)
-    return chunked_attention(q, k, v, causal=causal, window=window,
-                             chunk=chunk)
 
 
 class KVCache(NamedTuple):

@@ -15,8 +15,15 @@ hand-written backward, exactly like the FlashAttention backward:
     dQ  += scale · dS_c · k_c ;   dK_c = scale · dS_cᵀ · q
 
 Supports GQA grouping, causal masks, sliding windows, cross attention, and
-v-head-dim != qk-head-dim (MLA).  On TPU the chunk matmuls map to the MXU; no
-Mosaic kernel is needed, so the same code lowers on the CPU dry-run.
+v-head-dim != qk-head-dim (MLA), and lowers on any backend.  It is not fast on
+the TPU: each kv chunk writes a (queries x chunk) float32 score block to HBM
+and reads it back, so the chunk matmuls wait on bandwidth, and the causal
+scan computes the chunks above the diagonal too.  There, causal bf16
+self-attention at a length that is a multiple of 128 with one head size for
+q, k and v runs the Pallas flash kernel of ``repro.kernels.attention``
+instead (``models/attention._attention``).  This scan serves the other
+shapes: sliding windows, cross attention, MLA, float32 inputs, other
+lengths, and every shape on the CPU.
 """
 
 from __future__ import annotations

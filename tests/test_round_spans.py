@@ -75,6 +75,59 @@ def _smoke_round_text(monkeypatch=None):
             .compile().as_text()
 
 
+def _round_at(seq, tpu, monkeypatch):
+    """The one-client smoke round at ``seq`` tokens, ``_attention`` seeing a
+    TPU (the kernel interpreted) where ``tpu``: its compiled text and its
+    loss after one round."""
+    import repro.models.attention as attention
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import (TrainConfig, init_train_state,
+                                    make_train_step)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: tpu)
+    mesh = make_mesh(data=1)
+    cfg = get_smoke_config("smollm-135m")
+    tc = TrainConfig(protocol="stc", lr=0.05)
+    state = init_train_state(cfg, tc, n_clients=1, key=jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": toks}
+    with jax.set_mesh(mesh):
+        step = make_train_step(cfg, mesh, tc)
+        text = step.lower(state, batch).compile().as_text()
+        _, metrics = step(state, batch)
+    return text, float(metrics["loss"])
+
+
+def _unwrapped(components: set) -> set:
+    """Components without their transform labels: the forward's name stack
+    folds a scope into ``jvp(attention)``."""
+    out = set()
+    for part in components:
+        while part.endswith(")") and "(" in part:
+            part = part[part.index("(") + 1:-1]
+        out.add(part)
+    return out
+
+
+def test_attention_scope_is_in_the_compiled_round():
+    """On the CPU every attention of the round runs the jnp scan under
+    ``attention``, none under ``attention_kernel``."""
+    comps = _unwrapped(_components(_smoke_round_text()))
+    assert "attention" in comps
+    assert "attention_kernel" not in comps
+
+
+def test_a_tpu_round_runs_the_kernel_under_attention_kernel(monkeypatch):
+    """With the TPU condition stubbed, the round's attention (causal bf16
+    self-attention over 128 tokens) runs the kernel, under both scopes, and
+    the round's loss is the jnp scan's to bf16 rounding."""
+    text, loss = _round_at(128, True, monkeypatch)
+    assert {"attention", "attention_kernel"} <= _unwrapped(_components(text))
+    _, want = _round_at(128, False, monkeypatch)
+    assert loss == pytest.approx(want, rel=1e-3)
+
+
 def test_selection_scopes_are_in_the_compiled_program():
     tree = _tree(np.zeros(N, np.float32))
     text = jax.jit(lambda t: stc_compress_tree(t, P)).lower(tree) \

@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import (magnitude_histogram_batched, pack_bits_words,
                            stc_apply_batched, threshold_stats,
                            unpack_words_with_counts)
+from repro.kernels.attention import causal_gqa_attention
 
 N = 1 << 20            # one client update at real width
 WIRE_BITS = 1 << 20    # a Golomb stream of ~2^20 bits (2^15 words)
@@ -83,3 +84,21 @@ def test_pack_bits_compiles(one_chip):
 def test_unpack_words_compiles(one_chip):
     _compile(lambda w: unpack_words_with_counts(w, interpret=False),
              one_chip, ((WIRE_BITS // 32,), jnp.uint32))
+
+
+@pytest.mark.parametrize("b,h,kv", [(8, 9, 3), (2, 14, 2)],
+                         ids=["smollm-135m", "qwen2-0.5b"])
+def test_attention_compiles(one_chip, b, h, kv):
+    """The local step's attention at a cell's widths (2048 tokens, head size
+    64), forward and backward: three kernels, none interpreted."""
+    def fwd_bwd(q, k, v, do):
+        out, pullback = jax.vjp(
+            lambda q, k, v: causal_gqa_attention(q, k, v, interpret=False),
+            q, k, v)
+        return out, pullback(do)
+
+    text = _compile(fwd_bwd, one_chip, ((b, 2048, h, 64), jnp.bfloat16),
+                    ((b, 2048, kv, 64), jnp.bfloat16),
+                    ((b, 2048, kv, 64), jnp.bfloat16),
+                    ((b, 2048, h, 64), jnp.bfloat16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
